@@ -21,8 +21,9 @@ on both q=5 numbers (``--check-regression``).
 
 Knobs follow the other benchmarks: REPRO_SMOKE=1 shrinks to q in
 {5, 7} with short runs (CI / test_benchmarks_smoke); REPRO_FULL=1 (or
---full) extends to q=25; REPRO_CACHE_DIR enables the persistent
-compilation cache (cold/warm state is recorded in the json meta).
+--full) extends to q=25.  main() turns on the persistent compilation
+cache (`repro.bench.enable_compilation_cache`); the json meta records
+the cache directory in use (null when off).
 ``--repeats N`` overrides every entry's repeat count (the committed
 q=17 entry defaults to 1 — one steady-state run is ~2.5 min).
 REPRO_BENCH_OUT overrides the output path; without it, only a DIRECT
@@ -41,6 +42,7 @@ import os
 import sys
 import time
 
+import jax
 import numpy as np
 
 from repro.bench import (BenchEntry, bench_callable, check_regression,
@@ -184,7 +186,6 @@ def _bench_sweep(q: int = 5, cycles: int = 700, mode: str = "ugal_l",
 def run(fast: bool = True):
     full = os.environ.get("REPRO_FULL", "0") == "1" or not fast
     smoke = os.environ.get("REPRO_SMOKE", "0") == "1" and not full
-    cache_state, cache_dir = enable_compilation_cache()
     repeats_override = os.environ.get("REPRO_BENCH_REPEATS")
     # only a DELIBERATE baseline refresh (direct `python -m
     # benchmarks.engine_scaling`, which routes through main()) writes
@@ -254,8 +255,8 @@ def run(fast: bool = True):
     write_bench(out_path, "engine_scaling", entries,
                 extra_meta={"modes": ["ugal_l"],
                             "smoke": smoke, "full": full,
-                            "compile_cache": cache_state,
-                            "cache_dir": cache_dir})
+                            "cache_dir":
+                                jax.config.jax_compilation_cache_dir})
     return rows
 
 
@@ -270,6 +271,7 @@ def main() -> None:
                          "exit 1 on a >GATE_FACTOR regression of "
                          "cycles/sec or sweep points/sec")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     if args.check_regression:
         try:
@@ -279,7 +281,6 @@ def main() -> None:
             # a missing entry) — the sweep step regenerates it
             print(f"no baseline file {args.check_regression}; skipping")
             sys.exit(0)
-        enable_compilation_cache()
         entry, _ = _bench_point(5, cycles=300, repeats=3,
                                 measure_memory=False)
         ok, msg = check_regression(baseline, GATE_ENTRY, GATE_METRIC,

@@ -38,14 +38,13 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
-    # persistent compilation cache (REPRO_CACHE_DIR knob): must be
-    # configured before the first jit of the process; a warm directory
-    # turns every unchanged simulator compile into a deserialize
+    # persistent compilation cache: must be configured before the first
+    # jit of the process; a warm directory turns every unchanged
+    # simulator compile into a deserialize
     from repro.bench import enable_compilation_cache
     cache_state, cache_dir = enable_compilation_cache()
-    if cache_state != "off":
-        print(f"# compilation cache: {cache_state} ({cache_dir})",
-              file=sys.stderr)
+    print(f"# compilation cache: {cache_state} ({cache_dir})",
+          file=sys.stderr)
 
     print("name,us_per_call,derived")
     failures = 0

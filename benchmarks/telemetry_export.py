@@ -25,8 +25,7 @@ import time
 import jax
 import numpy as np
 
-from repro.bench import (bench_callable, enable_compilation_cache,
-                         lowering_breakdown, write_bench)
+from repro.bench import bench_callable, lowering_breakdown, write_bench
 from repro.core import build_slimfly
 from repro.sim import (SimConfig, SimTables, TelemetryConfig, make_traffic,
                        sweep_simulate)
@@ -64,7 +63,6 @@ def _lowering_entry(tables, traffic, cfg, tag):
 def run(fast: bool = True):
     full = os.environ.get("REPRO_FULL", "0") == "1" or not fast
     smoke = os.environ.get("REPRO_SMOKE", "0") == "1" and not full
-    enable_compilation_cache()
     out_dir = _artifact_dir()
 
     q = 19 if full else 5

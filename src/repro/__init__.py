@@ -1,23 +1,1 @@
-"""Slim Fly reproduction framework.
-
-Besides marking the package root, this module carries small
-forward-compat shims so the codebase is written against the CURRENT
-jax API surface while still running on the pinned toolchain image
-(jax 0.4.x): ``jax.shard_map`` graduated from
-``jax.experimental.shard_map`` (keyword ``check_rep`` became
-``check_vma``); we alias it when missing.  No behaviour changes on
-newer jax where the attribute already exists.
-"""
-
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, mesh, in_specs, out_specs, check_vma=True,
-                          **kwargs):
-        kwargs.pop("check_rep", None)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-    _jax.shard_map = _compat_shard_map
+"""Slim Fly reproduction framework."""

@@ -19,11 +19,12 @@ Methodology:
   entries use the near-free RSS high-water probe (``cheap=True`` /
   ``measure_memory="rss"``) instead of tracemalloc, whose hooks would
   dominate a q=17 run; ``peak_mem_bytes`` is therefore never null.
-- `enable_compilation_cache` points JAX's persistent compilation cache
-  at ``$REPRO_CACHE_DIR`` (no-op when unset) and reports whether the
-  directory was cold or warm, so benchmark wall times can distinguish
-  a real XLA compile from a cache deserialize.  CI persists the
-  directory across runs.
+- `enable_compilation_cache` turns on JAX's persistent compilation
+  cache (at ``$JAX_COMPILATION_CACHE_DIR`` where that is set, else at
+  ``<checkout>/.jax_cache``) and reports whether the directory was
+  cold or warm, so benchmark wall times can distinguish a real XLA
+  compile from a cache deserialize.  CI persists the directory across
+  runs.
 
 Schema (``BENCH_*.json``)::
 
@@ -98,36 +99,44 @@ def lowering_breakdown(fn, *args) -> dict:
     return {"trace_lower_s": t1 - t0, "xla_compile_s": t2 - t1}
 
 
+# <checkout>/.jax_cache: a fixed path, because the cache directory is
+# part of what a later process must find again (gitignored)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
 def enable_compilation_cache() -> tuple:
-    """Point JAX's persistent compilation cache at ``$REPRO_CACHE_DIR``.
+    """Turn on JAX's persistent compilation cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read it itself
+    and this sets no directory; otherwise the cache goes to the fixed
+    path `DEFAULT_CACHE_DIR` (``<checkout>/.jax_cache``).
 
     Returns ``(state, cache_dir)`` where state is:
-      - ``"off"``   — env var unset, nothing configured;
-      - ``"cold"``  — cache enabled, directory empty (compiles will
+      - ``"cold"``  — the directory holds no entry yet (compiles will
         populate it);
-      - ``"warm"``  — cache enabled and already populated (compiles
-        with unchanged HLO deserialize instead of re-running XLA).
+      - ``"warm"``  — it already holds entries (compiles with unchanged
+        HLO deserialize instead of re-running XLA).
 
-    Call this BEFORE the first jit of the process (benchmarks.run /
-    engine_scaling do it at main() entry).  The min-compile-time gate
-    is lowered to 1s so the big simulator scans always persist, and
-    entries are written on every backend including CPU.  The sweep
-    engine's tables-as-operands design is what makes the cache useful
-    for fault studies at all: masks live in operands, not in the HLO,
-    so every failure sample of a topology hits one cache entry
-    (DESIGN.md §10).
+    Call this BEFORE the first jit of the process (the entry points do
+    it at main() entry).  The min-compile-time gate is lowered to 1s so
+    the big simulator scans always persist, and entries are written on
+    every backend including CPU.  The sweep engine's tables-as-operands
+    design is what makes the cache useful for fault studies at all:
+    masks live in operands, not in the HLO, so every failure sample of
+    a topology hits one cache entry (DESIGN.md §10).
     """
-    cache_dir = os.environ.get("REPRO_CACHE_DIR", "")
-    if not cache_dir:
-        return "off", None
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    state = "warm" if any(
-        name.endswith("-cache") for name in os.listdir(cache_dir)) else "cold"
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    state = "warm" if os.path.isdir(cache_dir) and any(
+        name.endswith("-cache") for name in os.listdir(cache_dir)) else "cold"
     return state, cache_dir
 
 

@@ -5,7 +5,8 @@
 - alloc:       flit-simulator inner loops - W-round switch allocation
                and UGAL/VAL candidate scoring (DESIGN.md §9)
 ops.py holds the jit'd public wrappers; ref.py the pure-jnp oracles.
-On non-TPU hosts every kernel runs in interpret mode (bit-accurate).
+On the CPU backend every kernel runs in interpret mode (bit-accurate);
+any other backend compiles it with Mosaic (backend.py).
 """
 
 from .alloc import alloc_rounds, ugal_select

@@ -20,7 +20,9 @@ Both kernels call the SAME row-local math helpers as the pure-jnp
 oracles in `ref.py` (`_alloc_rounds_math`, `_ugal_score_math`), so the
 ``ref`` and ``pallas`` engine paths agree bit-for-bit by construction;
 tests/test_engine_scaling.py asserts full-`SimResult` equality.  On
-non-TPU hosts the kernels run in interpret mode, like `minplus`.
+the CPU backend the kernels run in interpret mode (`backend.py`), like
+every kernel of this package; tests/test_tpu_compile.py compiles them
+for a TPU v5e.
 """
 
 from __future__ import annotations
@@ -31,17 +33,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import ref
+from . import backend, ref
 
 __all__ = ["alloc_rounds", "alloc_rounds_pallas", "ugal_select",
            "ugal_select_pallas", "ALLOC_BLOCK_N", "UGAL_BLOCK_E"]
 
 ALLOC_BLOCK_N = 8            # routers per allocation block
 UGAL_BLOCK_E = 512           # endpoints per scoring block
-
-
-def _interpret_mode() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_rows(x, rows, fill=0):
@@ -64,7 +62,7 @@ def _alloc_kernel(cycle_ref, out_n_ref, ej_n_ref, sp_n_ref, cnt_n_ref,
         out_s_ref[...], ej_s_ref[...], sp_s_ref[...], cnt_s_ref[...],
         epr_ref[...], row0,
         W=W, P=P, V=V, PE=PE, p_budget=p_budget, NQ=NQ, R=R,
-        use_gather=False)
+        in_kernel=True)
     cs_n_ref[...] = cs_n
     es_n_ref[...] = es_n
     cs_s_ref[...] = cs_s
@@ -120,7 +118,7 @@ def alloc_rounds_pallas(cycle, out_net, ej_net, space_net, count_net,
             jax.ShapeDtypeStruct((rows, PE), jnp.int32),
             jax.ShapeDtypeStruct((rows, P), jnp.int32),
         ],
-        interpret=_interpret_mode(),
+        interpret=backend.interpret_mode(),
     )(cyc, out_net, ej_net, space_net, count_net,
       out_src, ej_src, space_src, count_src, epr)
     return tuple(o[:N] for o in outs)
@@ -191,7 +189,7 @@ def ugal_select_pallas(len_min, len_val, occ_min, occ_val,
         in_specs=[b1, bC, b1, bC],
         out_specs=b1,
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.int32),
-        interpret=_interpret_mode(),
+        interpret=backend.interpret_mode(),
     )(lm, lv, om, ov)
     return best[:E, 0]
 

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
+
 __all__ = ["decode_attention_pallas"]
 
 _NEG_BIG = -3.0e38
@@ -119,5 +121,5 @@ def decode_attention_pallas(q, k, v, length=None, *, bs: int = 512,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=backend.interpret_mode(),
     )(length, q, k, v)

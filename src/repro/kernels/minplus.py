@@ -11,6 +11,10 @@ TPU adaptation (DESIGN.md §3): BFS pointer-chasing is replaced by dense
 blocked semiring algebra.  The MXU cannot evaluate a (min,+) contraction,
 so the inner loop is a VPU-vectorized rank-1 sweep over the K tile: each
 step does a [bm, bn] broadcast-add + min, which maps onto 8x128 VREGs.
+Mosaic refuses a dynamic slice of a value, and a dynamic column read
+from the ref too (its lane offset is not provably a multiple of 128), so
+the A tile rides the loop carry and is rotated one lane per step: the
+current column is always lane 0.
 Block shapes keep the working set (3 tiles + accumulator) well inside VMEM:
 bm = bn = bk = 128  =>  4 * 128*128*4 B = 256 KiB.
 
@@ -26,6 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import backend
 
 __all__ = ["minplus_pallas", "DEFAULT_BLOCK"]
 
@@ -41,17 +48,16 @@ def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _BIG)
 
-    a = a_ref[0]  # [bm, bk]
-    b = b_ref[0]  # [bk, bn]
+    def body(kk, carry):
+        # rank-1 (min,+) update: acc = min(acc, a[:, kk] + b[kk, :]).
+        # Column kk of A sits in lane 0 of the carried, lane-rotated
+        # tile; row kk of B is read from its ref
+        a, acc = carry
+        acc = jnp.minimum(acc, a[:, :1] + b_ref[0, pl.ds(kk, 1), :])
+        return pltpu.roll(a, bk - 1, 1), acc
 
-    def body(kk, acc):
-        # rank-1 (min,+) update: acc = min(acc, a[:, kk] + b[kk, :])
-        col = lax.dynamic_slice_in_dim(a, kk, 1, axis=1)      # [bm, 1]
-        row = lax.dynamic_slice_in_dim(b, kk, 1, axis=0)      # [1, bn]
-        return jnp.minimum(acc, col + row)
-
-    acc = lax.fori_loop(0, bk, body, o_ref[...][0])
-    o_ref[...] = acc[None]
+    _, acc = lax.fori_loop(0, bk, body, (a_ref[0], o_ref[0]))
+    o_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -80,14 +86,9 @@ def minplus_pallas(a: jax.Array, b: jax.Array, block: int = DEFAULT_BLOCK):
         ],
         out_specs=pl.BlockSpec((1, block, block), lambda bt, i, j, k: (bt, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), a.dtype),
-        interpret=_interpret_mode(),
+        interpret=backend.interpret_mode(),
     )(a, b)
     out = out[:, :M, :N]
     # saturate accumulated "inf + inf" values back to _BIG
     out = jnp.minimum(out, _BIG)
     return out[0] if squeeze else out
-
-
-def _interpret_mode() -> bool:
-    """Pallas TPU kernels run in interpret mode on CPU-only hosts."""
-    return jax.default_backend() != "tpu"

@@ -90,11 +90,24 @@ def decode_attention_ref(q, k, v, scale: float | None = None, length=None,
 KSHIFT = 256
 
 
+def _excl_prefix(x, in_kernel: bool):
+    """Exclusive prefix sum of int32 `x` [B, n] along its columns.
+
+    Mosaic has no cumsum lowering, so the kernel form sums the columns
+    strictly left of each column through a triangular compare mask."""
+    if not in_kernel:
+        return jnp.cumsum(x, axis=1) - x
+    n = x.shape[1]
+    left = (lax.broadcasted_iota(jnp.int32, (1, n, n), 2)
+            < lax.broadcasted_iota(jnp.int32, (1, n, n), 1))  # [1, j, i]
+    return jnp.sum(jnp.where(left, x[:, None, :], 0), axis=2)
+
+
 def _alloc_rounds_math(cycle, out_n, ej_n, sp_n, cnt_n,
                        out_s, ej_s, sp_s, cnt_s, epr, row0,
                        *, W: int, P: int, V: int, PE: int,
                        p_budget: int, NQ: int, R: int,
-                       use_gather: bool = True):
+                       in_kernel: bool = False):
     """W rounds of matching for a block of routers.
 
     Shapes (B = routers in this block, PV = P*V; the W axis is LAST so
@@ -114,6 +127,11 @@ def _alloc_rounds_math(cycle, out_n, ej_n, sp_n, cnt_n,
     channel carries at most one packet per cycle, so one [B, P] index
     array captures every arrival (the engine turns it into dense
     per-(router, port) gathers instead of a scatter).
+
+    Masks are int32 0/1 throughout: Mosaic refuses bool arrays that are
+    concatenated, sliced on a trailing axis or broadcast over a new
+    axis.  `in_kernel` selects the Mosaic-lowerable forms of the prefix
+    count and the winner test; both forms grant identically.
     """
     B = cnt_n.shape[0]
     PV = P * V
@@ -134,9 +152,9 @@ def _alloc_rounds_math(cycle, out_n, ej_n, sp_n, cnt_n,
     net_first = (cycle % 2) == 0
     base = cycle * jnp.int32(7919)
 
-    granted_n = jnp.zeros((B, PV), bool)
-    granted_s = jnp.zeros((B, PE), bool)
-    chan_taken = jnp.zeros((B, P), bool)
+    free_n = jnp.ones((B, PV), i32)                  # 1 = not yet granted
+    free_s = jnp.ones((B, PE), i32)
+    chan_free = jnp.ones((B, P), i32)
     budget = jnp.full((B, 1), p_budget, i32)
     cs_n = jnp.full((B, PV), -1, i32)
     es_n = jnp.full((B, PV), -1, i32)
@@ -144,39 +162,36 @@ def _alloc_rounds_math(cycle, out_n, ej_n, sp_n, cnt_n,
     es_s = jnp.full((B, PE), -1, i32)
     win_req = jnp.full((B, P), -1, i32)
 
-    # hoisted across rounds: request -> channel one-hot (out ports are
-    # fixed per window slot) and the rotation base priorities
-    out_kw = jnp.concatenate([out_n, out_s], axis=1)     # [B, K, W]
-    match_all = out_kw[:, None, :, :] == chan_ids[..., None]  # [B,P,K,W]
+    # hoisted across rounds: the rotation base priorities
     qidx_k = jnp.concatenate([qidx_n, qidx_s], axis=1)
     rot0 = (qidx_k + base) % R                           # [B, K]
 
     for w in range(W):
-        vn = (cnt_n > w) & ~granted_n
-        vs = (cnt_s > w) & ~granted_s
-        ejn = ej_n[:, :, w] != 0
-        ejs = ej_s[:, :, w] != 0
-        spn = sp_n[:, :, w] != 0
-        sps = sp_s[:, :, w] != 0
+        vn = jnp.where(cnt_n > w, free_n, 0)
+        vs = jnp.where(cnt_s > w, free_s, 0)
+        ejn = (ej_n[:, :, w] != 0).astype(i32)
+        ejs = (ej_s[:, :, w] != 0).astype(i32)
+        spn = (sp_n[:, :, w] != 0).astype(i32)
+        sps = (sp_s[:, :, w] != 0).astype(i32)
 
         # --- ejection grants: rotating rank over the router's net
         # queues (start column rotates with the cycle), endpoints ranked
         # before/after by cycle parity, against the shared budget of p
         # ejection ports.  rank = exclusive prefix count in rotated
         # order, computed in closed form instead of roll+cumsum+roll.
-        mn = (vn & ejn).astype(i32)
-        ms = (vs & ejs).astype(i32)
-        cn = jnp.cumsum(mn, axis=1) - mn             # exclusive prefix
+        mn = vn * ejn
+        ms = vs * ejs
+        cn = _excl_prefix(mn, in_kernel)
         sn = mn.sum(axis=1, keepdims=True)
         c_at = jnp.sum(jnp.where(col_pv == s_rot, cn, 0), axis=1,
                        keepdims=True)
         rank_n = cn - c_at + jnp.where(col_pv < s_rot, sn, 0)
-        cs_pre = jnp.cumsum(ms, axis=1) - ms
+        cs_pre = _excl_prefix(ms, in_kernel)
         ss = ms.sum(axis=1, keepdims=True)
         rank_nf = rank_n + jnp.where(net_first, 0, ss)
         rank_sf = cs_pre + jnp.where(net_first, sn, 0)
-        g_ej_n = (mn > 0) & (rank_nf < budget)
-        g_ej_s = (ms > 0) & (rank_sf < budget)
+        g_ej_n = jnp.where(rank_nf < budget, mn, 0)
+        g_ej_s = jnp.where(rank_sf < budget, ms, 0)
         budget = (budget - g_ej_n.sum(axis=1, keepdims=True)
                   - g_ej_s.sum(axis=1, keepdims=True))
 
@@ -186,39 +201,39 @@ def _alloc_rounds_math(cycle, out_n, ej_n, sp_n, cnt_n,
         # packing (rot, request index) into rot*KSHIFT + idx lets one
         # min-reduction produce both the winner's priority and its
         # identity; a channel with any eligible request always grants.
-        elig_n = vn & ~ejn & spn
-        elig_s = vs & ~ejs & sps
+        elig_n = vn * (1 - ejn) * spn
+        elig_s = vs * (1 - ejs) * sps
         cmb = ((rot0 + jnp.int32(w * 131)) % R) * KSHIFT + col_k  # [B, K]
-        out_all = out_kw[:, :, w]
-        elig = jnp.concatenate([elig_n, elig_s], axis=1)
-        live = (match_all[..., w]
-                & ~chan_taken[:, :, None] & elig[:, None, :])  # [B, P, K]
+        out_all = jnp.concatenate([out_n[:, :, w], out_s[:, :, w]], axis=1)
+        elig = jnp.concatenate([elig_n, elig_s], axis=1)          # [B, K]
+        live = ((out_all[:, None, :] == chan_ids)
+                & (chan_free[:, :, None] * elig[:, None, :] != 0))  # [B,P,K]
         cmin = jnp.min(jnp.where(live, cmb[:, None, :], intmax),
                        axis=2)                       # [B, P]
         won = cmin < intmax
-        if use_gather:
+        if in_kernel:
+            # gather-free winner test (cmb values are distinct, so ==
+            # picks exactly the channel's winner)
+            hit = jnp.where(live & (cmb[:, None, :] == cmin[:, :, None]),
+                            1, 0)
+            win_all = jnp.max(hit, axis=1)
+        else:
             # per-request winner test via a [B, K] row gather of the
-            # channel minima — cheap on CPU/GPU.  cmb values are
-            # distinct across requests, so equality alone identifies
-            # the winner (taken/ineligible rows can never match).
+            # channel minima — cheap on CPU/GPU; taken/ineligible rows
+            # can never match
             cmin_at = jnp.take_along_axis(cmin, jnp.maximum(out_all, 0),
                                           axis=1)
-            win_all = elig & (out_all >= 0) & (cmb == cmin_at)
-        else:
-            # gather-free form for the TPU kernel (identical winners:
-            # cmb values are distinct, so == picks exactly one)
-            win_all = (live & (cmb[:, None, :] == cmin[:, :, None])
-                       ).any(axis=1)
+            win_all = jnp.where((out_all >= 0) & (cmb == cmin_at), elig, 0)
         win_n, win_s = win_all[:, :PV], win_all[:, PV:]
-        chan_taken = chan_taken | won
+        chan_free = jnp.where(won, 0, chan_free)
         win_req = jnp.where(won, cmin % KSHIFT, win_req)
 
-        granted_n = granted_n | win_n | g_ej_n
-        granted_s = granted_s | win_s | g_ej_s
-        cs_n = jnp.where(win_n, w, cs_n)
-        es_n = jnp.where(g_ej_n, w, es_n)
-        cs_s = jnp.where(win_s, w, cs_s)
-        es_s = jnp.where(g_ej_s, w, es_s)
+        free_n = free_n * (1 - win_n) * (1 - g_ej_n)
+        free_s = free_s * (1 - win_s) * (1 - g_ej_s)
+        cs_n = jnp.where(win_n != 0, w, cs_n)
+        es_n = jnp.where(g_ej_n != 0, w, es_n)
+        cs_s = jnp.where(win_s != 0, w, cs_s)
+        es_s = jnp.where(g_ej_s != 0, w, es_s)
 
     return cs_n, es_n, cs_s, es_s, win_req
 
@@ -233,7 +248,7 @@ def alloc_rounds_ref(cycle, out_net, ej_net, space_net, count_net,
         count_net, out_src, ej_src, space_src, count_src,
         epr_index.reshape(-1, 1), jnp.int32(0),
         W=W, P=P, V=V, PE=PE, p_budget=p_budget, NQ=NQ, R=R,
-        use_gather=True)
+        in_kernel=False)
 
 
 # ------------------------------------------------------------ UGAL score --

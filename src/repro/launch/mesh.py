@@ -9,8 +9,17 @@ import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all `Auto`: the LM stack leaves output
+    shardings to the partitioner (its rules name only parameter and
+    batch specs), which jax's default `Explicit` axes refuse at every
+    gather or contraction whose output sharding is ambiguous."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,12 +27,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 x 16 x 16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist (tests / smoke runs)."""
     n = jax.device_count()
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _auto_mesh((n // model_parallel, model_parallel),
+                      ("data", "model"))

@@ -78,6 +78,7 @@ def test_sharded_train_step_runs_on_8_devices():
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs import get, reduced
     from repro.dist.sharding import batch_spec, param_specs, shard_params
+    from repro.launch.mesh import make_local_mesh
     from repro.models.model import init_params, loss_fn
     from repro.optim.adamw import AdamWConfig, init_opt_state
     from repro.train.loop import TrainConfig, make_train_step
@@ -86,7 +87,7 @@ def test_sharded_train_step_runs_on_8_devices():
     cfg = reduced(get("gemma2-2b"))
     cfg = dataclasses.replace(cfg, dp_axes=("data",), tp_axis="model",
                               scan_layers=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(model_parallel=4)      # (2, 4), Auto axes
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch = dict(tokens=jax.random.randint(jax.random.PRNGKey(1), (4, 32),
                                            0, cfg.vocab))

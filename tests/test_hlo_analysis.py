@@ -60,7 +60,10 @@ def test_collective_bytes_sharded_matmul():
     w = jax.ShapeDtypeStruct((512, 128), jnp.bfloat16,
                              sharding=NamedSharding(mesh, P("m", None)))
     def f(x, w):
-        return jnp.square((x @ w).astype(jnp.float32)).sum()
+        # the contraction dim is sharded: the replicated output sharding
+        # must be named, and it is what makes the all-reduce
+        y = jnp.matmul(x, w, out_sharding=NamedSharding(mesh, P()))
+        return jnp.square(y.astype(jnp.float32)).sum()
     with mesh:
         t = jax.jit(f).lower(x, w).compile().as_text()
     a = analyze_hlo(t)

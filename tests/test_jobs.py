@@ -48,7 +48,7 @@ _GOLDEN = [
      250.0, 3840, 61845, 57855),
     (lambda: ring_all_reduce(12, 5),
      dict(mode="ugal_l", placement="spread", chunk=96, seed=3),
-     182.0, 1320, 24615, 22478),
+     183.0, 1320, 24559, 22423),
     (lambda: stencil((4, 4), 8, iters=2),
      dict(mode="min", placement="blocked", chunk=100, seed=1),
      98.0, 1024, 6646, 4332),
@@ -57,6 +57,8 @@ _GOLDEN = [
 
 @pytest.mark.parametrize("case", range(len(_GOLDEN)))
 def test_golden_single_job_outcomes(sf5_tables, case):
+    """Values recorded under jax 0.9.0 (case 1, the only one that draws
+    route RNG, was re-pinned there; the MIN cases are version-free)."""
     wl_fn, kw, makespan, flits, done_sum, start_sum = _GOLDEN[case]
     r = run_workload(sf5_tables, wl_fn(), WorkloadSimConfig(**kw))
     assert r.completed
