@@ -1,0 +1,10 @@
+"""Share of device busy time in the `closed.ready` stage of a simulated
+cycle: the closed loop's ready set: the dependency gather over every
+message. Self time of the ops the compiled runner's `op_name` metadata
+puts under the scope, over busy time (`bench/stages.py`)."""
+
+from bench import stages
+
+
+def read(ctx):
+    return stages.time_share(ctx, "closed.ready")

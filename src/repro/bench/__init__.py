@@ -8,10 +8,9 @@ CI gate can fail on hot-path regressions.
 
 from .harness import (BenchEntry, bench_callable, check_regression,
                       enable_compilation_cache, load_bench,
-                      lowering_breakdown, peak_memory_bytes, repo_stamp,
-                      rss_hwm_bytes, write_bench)
+                      peak_memory_bytes, repo_stamp, rss_hwm_bytes,
+                      write_bench)
 
 __all__ = ["BenchEntry", "bench_callable", "check_regression",
-           "enable_compilation_cache", "load_bench", "lowering_breakdown",
-           "peak_memory_bytes", "repo_stamp", "rss_hwm_bytes",
-           "write_bench"]
+           "enable_compilation_cache", "load_bench", "peak_memory_bytes",
+           "repo_stamp", "rss_hwm_bytes", "write_bench"]

@@ -53,7 +53,7 @@ from typing import Callable, Optional
 __all__ = ["BenchEntry", "bench_callable", "peak_memory_bytes",
            "rss_hwm_bytes", "enable_compilation_cache",
            "write_bench", "load_bench", "check_regression",
-           "repo_stamp", "lowering_breakdown"]
+           "repo_stamp"]
 
 SCHEMA_VERSION = 1
 
@@ -82,21 +82,6 @@ def repo_stamp(telemetry: bool = False) -> dict:
         _GIT_SHA_CACHE.append(sha)
     return {"git_sha": _GIT_SHA_CACHE[0], "jax_version": jax.__version__,
             "telemetry": bool(telemetry)}
-
-
-def lowering_breakdown(fn, *args) -> dict:
-    """Split a jitted callable's pre-execution cost into tracing/
-    lowering vs XLA compilation, in seconds: ``{"trace_lower_s": ..,
-    "xla_compile_s": ..}``.  Telemetry changes the traced graph (extra
-    carry arrays, counter updates), so benchmarks report both phases
-    separately to show where a config's compile tax actually goes.
-    `fn` must be a jax.jit-wrapped callable (it needs `.lower`)."""
-    t0 = time.perf_counter()
-    lowered = fn.lower(*args)
-    t1 = time.perf_counter()
-    lowered.compile()
-    t2 = time.perf_counter()
-    return {"trace_lower_s": t1 - t0, "xla_compile_s": t2 - t1}
 
 
 # <checkout>/.jax_cache: a fixed path, because the cache directory is

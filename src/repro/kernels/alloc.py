@@ -22,7 +22,9 @@ oracles in `ref.py` (`_alloc_rounds_math`, `_ugal_score_math`), so the
 tests/test_engine_scaling.py asserts full-`SimResult` equality.  On
 the CPU backend the kernels run in interpret mode (`backend.py`), like
 every kernel of this package; tests/test_tpu_compile.py compiles them
-for a TPU v5e.
+for a TPU v5e.  Each `pallas_call` is named (`name=`), so the compiled
+kernel is `<kernel>_pallas[.<n>]` in the HLO and in a profile, however
+the jitted wrappers around it are named.
 """
 
 from __future__ import annotations
@@ -119,6 +121,7 @@ def alloc_rounds_pallas(cycle, out_net, ej_net, space_net, count_net,
             jax.ShapeDtypeStruct((rows, P), jnp.int32),
         ],
         interpret=backend.interpret_mode(),
+        name="alloc_rounds_pallas",
     )(cyc, out_net, ej_net, space_net, count_net,
       out_src, ej_src, space_src, count_src, epr)
     return tuple(o[:N] for o in outs)
@@ -190,6 +193,7 @@ def ugal_select_pallas(len_min, len_val, occ_min, occ_val,
         out_specs=b1,
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.int32),
         interpret=backend.interpret_mode(),
+        name="ugal_select_pallas",
     )(lm, lv, om, ov)
     return best[:E, 0]
 

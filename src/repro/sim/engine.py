@@ -291,6 +291,7 @@ class SwitchCore:
                 jnp.zeros((self.n_ep, Qs, PK), jnp.int32),
                 jnp.zeros((self.n_ep,), jnp.int32))
 
+    @jax.named_scope("switch.occupancy")
     def occupancy(self, nq_count):
         """Credit view: occ[r, o] = downstream input-queue depth."""
         safe_nbr = jnp.maximum(self.nbr, 0)
@@ -298,6 +299,7 @@ class SwitchCore:
         occ = nq_count[safe_nbr, safe_rev, :].sum(-1)          # [N, P]
         return jnp.where(self.nbr >= 0, occ, BIG)
 
+    @jax.named_scope("switch.inject")
     def inject(self, sq_pkt, sq_count, want, new_pkt):
         """Masked tail enqueue into the per-endpoint source FIFOs.
 
@@ -314,6 +316,7 @@ class SwitchCore:
     def _dist32(self, s, t):
         return self.dist[s, t].astype(jnp.int32)
 
+    @jax.named_scope("switch.route")
     def route_decision(self, dst_r, occ, key):
         """Per-endpoint injection-time path choice -> (inter, phase)."""
         mode, C, N, n_ep = self.mode, self.C, self.N, self.n_ep
@@ -459,21 +462,23 @@ class SwitchCore:
                 pad[-2] = (0, W - wn)
                 win = jnp.pad(win, pad)
             return win
-        win_net = head_window(nq_pkt, Qn)                      # [N,P,V,W,PK]
-        win_src = head_window(sq_pkt, Qs)                      # [n_ep,W,PK]
-
-        r_bcast = jnp.broadcast_to(self.routers_n[..., None], (N, P, V, W))
-        ep_bcast = jnp.broadcast_to(self.ep_router[:, None], (n_ep, W))
-        n_out, n_vc, n_ej = self._desires(win_net, r_bcast, occ)
-        s_out, s_vc, s_ej = self._desires(win_src, ep_bcast, occ)
+        with jax.named_scope("switch.desires"):
+            win_net = head_window(nq_pkt, Qn)                  # [N,P,V,W,PK]
+            win_src = head_window(sq_pkt, Qs)                  # [n_ep,W,PK]
+            r_bcast = jnp.broadcast_to(self.routers_n[..., None],
+                                       (N, P, V, W))
+            ep_bcast = jnp.broadcast_to(self.ep_router[:, None], (n_ep, W))
+            n_out, n_vc, n_ej = self._desires(win_net, r_bcast, occ)
+            s_out, s_vc, s_ej = self._desires(win_src, ep_bcast, occ)
 
         def space_of(router, out, vc):
             dr = nbr[router, jnp.maximum(out, 0)]
             dp = rev_port[router, jnp.maximum(out, 0)]
             depth = nq_count[jnp.maximum(dr, 0), jnp.maximum(dp, 0), vc]
             return (out >= 0) & (dr >= 0) & (depth < Qn)
-        n_sp = space_of(r_bcast, n_out, n_vc)
-        s_sp = space_of(ep_bcast, s_out, s_vc)
+        with jax.named_scope("switch.space"):
+            n_sp = space_of(r_bcast, n_out, n_vc)
+            s_sp = space_of(ep_bcast, s_out, s_vc)
 
         # ---- router-major request arrays for the allocation kernel
         # (W-last layout: the [N,P,V,W] desire arrays reshape in free)
@@ -491,103 +496,109 @@ class SwitchCore:
             g = y[jnp.maximum(self.epr_index, 0)]
             return jnp.where((self.epr_index >= 0)[:, None, None], g, 0)
 
-        live_q = (nbr >= 0)[:, :, None]
-        cnt_net = jnp.where(live_q, nq_count, 0).reshape(N, PV)
-        cs_rows = sq_count.reshape(n_epr, PE)[jnp.maximum(self.epr_index, 0)]
-        cnt_src = jnp.where((self.epr_index >= 0)[:, None], cs_rows, 0)
+        with jax.named_scope("switch.alloc"):
+            live_q = (nbr >= 0)[:, :, None]
+            cnt_net = jnp.where(live_q, nq_count, 0).reshape(N, PV)
+            cs_rows = sq_count.reshape(n_epr, PE)[
+                jnp.maximum(self.epr_index, 0)]
+            cnt_src = jnp.where((self.epr_index >= 0)[:, None], cs_rows, 0)
 
-        i32 = jnp.int32
-        chan_n, ej_n, chan_s, ej_s, win_req = alloc_rounds(
-            cycle, rm_net(n_out), rm_net(n_ej.astype(i32)),
-            rm_net(n_sp.astype(i32)), cnt_net,
-            rm_src(s_out), rm_src(s_ej.astype(i32)),
-            rm_src(s_sp.astype(i32)), cnt_src, self.epr_index,
-            W=W, P=P, V=V, PE=PE, p_budget=self.p, NQ=self.NQ, R=self.R,
-            use_pallas=self.use_pallas)
-        cs_net = chan_n.reshape(N, P, V)           # granted window offset
-        ej_net = ej_n.reshape(N, P, V)             # (-1 = none), by kind
-        cs_src = chan_s[ebr].reshape(n_ep)
-        ej_src = ej_s[ebr].reshape(n_ep)
+            i32 = jnp.int32
+            chan_n, ej_n, chan_s, ej_s, win_req = alloc_rounds(
+                cycle, rm_net(n_out), rm_net(n_ej.astype(i32)),
+                rm_net(n_sp.astype(i32)), cnt_net,
+                rm_src(s_out), rm_src(s_ej.astype(i32)),
+                rm_src(s_sp.astype(i32)), cnt_src, self.epr_index,
+                W=W, P=P, V=V, PE=PE, p_budget=self.p, NQ=self.NQ, R=self.R,
+                use_pallas=self.use_pallas)
+            cs_net = chan_n.reshape(N, P, V)           # granted window offset
+            ej_net = ej_n.reshape(N, P, V)             # (-1 = none), by kind
+            cs_src = chan_s[ebr].reshape(n_ep)
+            ej_src = ej_s[ebr].reshape(n_ep)
 
         # ---- engine-specific ejection stats, one fold per round
-        for w in range(W):
-            eject_acc = eject_fold(eject_acc, ej_net == w, ej_src == w,
-                                   win_net[:, :, :, w], win_src[:, w],
-                                   cycle)
+        with jax.named_scope("switch.fold"):
+            for w in range(W):
+                eject_acc = eject_fold(eject_acc, ej_net == w, ej_src == w,
+                                       win_net[:, :, :, w], win_src[:, w],
+                                       cycle)
 
         # ---- arrivals, as a dense per-(router, port) view: each input
         # port receives at most one packet per cycle, always from its
         # unique upstream channel, so `win_req` of the upstream router
         # identifies the arriving packet with [N, P]-sized gathers — no
         # R-row scatter (XLA CPU scatters serialise per row)
-        u_c = jnp.maximum(nbr, 0)                  # upstream router [N,P]
-        uo_c = jnp.maximum(rev_port, 0)            # its out port
-        wi = win_req[u_c, uo_c]                    # winning request id
-        valid = (nbr >= 0) & (wi >= 0)
-        is_net = wi < PV
-        wi_n = jnp.clip(wi, 0, PV - 1)
-        eid = jnp.clip(self.epr_index[u_c] * PE + jnp.maximum(wi - PV, 0),
-                       0, n_ep - 1)
-        slot = jnp.maximum(
-            jnp.where(is_net, chan_n[u_c, wi_n], cs_src[eid]), 0)
-        win_net_pm = win_net.reshape(N, PV, W, PK)
-        pkt = jnp.where(is_net[..., None],
-                        win_net_pm[u_c, wi_n, slot],      # [N,P,PK]
-                        win_src[eid, slot])
-        vc = jnp.where(is_net,
-                       n_vc.reshape(N, PV, W)[u_c, wi_n, slot],
-                       s_vc[eid, slot])
-        here = jnp.arange(N)[:, None]
-        w2 = bump_hops_word(pkt[..., 2],
-                            (here == pk_inter(pkt)).astype(jnp.int32))
-        pkt = jnp.concatenate([pkt[..., :2], w2[..., None]], axis=-1)
-        arrived = valid[..., None] & (jnp.arange(V) == vc[..., None])
+        with jax.named_scope("switch.arrivals"):
+            u_c = jnp.maximum(nbr, 0)                  # upstream router [N,P]
+            uo_c = jnp.maximum(rev_port, 0)            # its out port
+            wi = win_req[u_c, uo_c]                    # winning request id
+            valid = (nbr >= 0) & (wi >= 0)
+            is_net = wi < PV
+            wi_n = jnp.clip(wi, 0, PV - 1)
+            eid = jnp.clip(self.epr_index[u_c] * PE + jnp.maximum(wi - PV, 0),
+                           0, n_ep - 1)
+            slot = jnp.maximum(
+                jnp.where(is_net, chan_n[u_c, wi_n], cs_src[eid]), 0)
+            win_net_pm = win_net.reshape(N, PV, W, PK)
+            pkt = jnp.where(is_net[..., None],
+                            win_net_pm[u_c, wi_n, slot],      # [N,P,PK]
+                            win_src[eid, slot])
+            vc = jnp.where(is_net,
+                           n_vc.reshape(N, PV, W)[u_c, wi_n, slot],
+                           s_vc[eid, slot])
+            here = jnp.arange(N)[:, None]
+            w2 = bump_hops_word(pkt[..., 2],
+                                (here == pk_inter(pkt)).astype(jnp.int32))
+            pkt = jnp.concatenate([pkt[..., :2], w2[..., None]], axis=-1)
+            arrived = valid[..., None] & (jnp.arange(V) == vc[..., None])
 
         # ---- telemetry (data-only: nothing below reads tel_state).
         # Placed before the dequeue so the counters see the same
         # cycle-start queue depths the kernel saw.
         if tel_state is not None and self.tel.enabled:
-            cs_t, tr_t = tel_state
-            if self.tel.counters:
-                cs_t = tel.counters.count_cycle(cs_t, nq_count)
-                cs_t = tel.counters.count_alloc(
-                    cs_t, self, cycle, win_net, win_src, win_req,
-                    cs_net, ej_net, cs_src, ej_src, cnt_net, sq_count)
-            if self.tel.trace:
-                tr_t = tel.trace.trace_alloc(
-                    tr_t, self, cycle, valid, pkt, win_net, win_src,
-                    ej_net, ej_src, trace_sample, trace_extra)
-            tel_state = tel.TelemetryState(cs_t, tr_t)
+            with jax.named_scope("switch.telemetry"):
+                cs_t, tr_t = tel_state
+                if self.tel.counters:
+                    cs_t = tel.counters.count_cycle(cs_t, nq_count)
+                    cs_t = tel.counters.count_alloc(
+                        cs_t, self, cycle, win_net, win_src, win_req,
+                        cs_net, ej_net, cs_src, ej_src, cnt_net, sq_count)
+                if self.tel.trace:
+                    tr_t = tel.trace.trace_alloc(
+                        tr_t, self, cycle, valid, pkt, win_net, win_src,
+                        ej_net, ej_src, trace_sample, trace_extra)
+                tel_state = tel.TelemetryState(cs_t, tr_t)
 
         # ---- dequeue + compaction: removing the granted packet at
         # offset g is a static-shift select (slots >= g take their
         # successor) — order-preserving, no gathers or scatters; then
         # the arrival is inserted at the post-dequeue tail by a masked
         # select (one arrival per (router, port) per cycle)
-        g_net = jnp.maximum(cs_net, ej_net)
-        g_src = jnp.maximum(cs_src, ej_src)
-        deq_net = (g_net >= 0).astype(jnp.int32)
-        deq_src = (g_src >= 0).astype(jnp.int32)
+        with jax.named_scope("switch.compaction"):
+            g_net = jnp.maximum(cs_net, ej_net)
+            g_src = jnp.maximum(cs_src, ej_src)
+            deq_net = (g_net >= 0).astype(jnp.int32)
+            deq_src = (g_src >= 0).astype(jnp.int32)
 
-        sidx = jnp.arange(Qn, dtype=jnp.int32)
-        up_net = jnp.concatenate(
-            [nq_pkt[:, :, :, 1:], jnp.zeros_like(nq_pkt[:, :, :, :1])],
-            axis=3)
-        drop_m = (g_net[..., None] >= 0) & (sidx >= g_net[..., None])
-        nq_pkt = jnp.where(drop_m[..., None], up_net, nq_pkt)
-        tail = (nq_count - deq_net)[..., None]             # [N,P,V,1]
-        ins = arrived[..., None] & (sidx == tail)          # [N,P,V,Qn]
-        nq_pkt = jnp.where(ins[..., None], pkt[:, :, None, None, :],
-                           nq_pkt)
+            sidx = jnp.arange(Qn, dtype=jnp.int32)
+            up_net = jnp.concatenate(
+                [nq_pkt[:, :, :, 1:], jnp.zeros_like(nq_pkt[:, :, :, :1])],
+                axis=3)
+            drop_m = (g_net[..., None] >= 0) & (sidx >= g_net[..., None])
+            nq_pkt = jnp.where(drop_m[..., None], up_net, nq_pkt)
+            tail = (nq_count - deq_net)[..., None]             # [N,P,V,1]
+            ins = arrived[..., None] & (sidx == tail)          # [N,P,V,Qn]
+            nq_pkt = jnp.where(ins[..., None], pkt[:, :, None, None, :],
+                               nq_pkt)
 
-        s_sidx = jnp.arange(Qs, dtype=jnp.int32)
-        up_src = jnp.concatenate(
-            [sq_pkt[:, 1:], jnp.zeros_like(sq_pkt[:, :1])], axis=1)
-        s_drop = (g_src[:, None] >= 0) & (s_sidx >= g_src[:, None])
-        sq_pkt = jnp.where(s_drop[..., None], up_src, sq_pkt)
+            s_sidx = jnp.arange(Qs, dtype=jnp.int32)
+            up_src = jnp.concatenate(
+                [sq_pkt[:, 1:], jnp.zeros_like(sq_pkt[:, :1])], axis=1)
+            s_drop = (g_src[:, None] >= 0) & (s_sidx >= g_src[:, None])
+            sq_pkt = jnp.where(s_drop[..., None], up_src, sq_pkt)
 
-        nq_count = nq_count + arrived.astype(jnp.int32) - deq_net
-        sq_count = sq_count - deq_src
+            nq_count = nq_count + arrived.astype(jnp.int32) - deq_net
+            sq_count = sq_count - deq_src
 
         if tel_state is None:
             return (nq_pkt, nq_count, sq_pkt, sq_count, eject_acc)
@@ -759,11 +770,38 @@ def _assemble_result(tables: SimTables, traffic: Traffic, cfg: SimConfig,
     )
 
 
+def _init_carry(core: SwitchCore, seed) -> tuple:
+    """The open-loop scan's initial carry (donated to the runner)."""
+    return core.init_queues() + (jax.random.PRNGKey(seed),
+                                 tel.init_state(core.tel, core))
+
+
+def compiled_runner_hlo() -> list:
+    """Optimised HLO text of each open-loop runner compiled in this
+    process.  Its `op_name` metadata carries the stage scopes
+    (`switch.*`), which is how a profile's device ops are attributed to
+    the stages of a cycle.  Lowering again with the runner's abstract
+    arguments reuses the executable that ran (from JAX's caches)."""
+    out = []
+    for _, _, (core, fn) in list(_OPEN_LOOP_CACHE.values()):
+        carry = jax.eval_shape(lambda: _init_carry(core, 0))
+        rate = jax.ShapeDtypeStruct((), jnp.float32)
+        out.append(fn.lower(carry, rate).compile().as_text())
+    return out
+
+
 def simulate(tables: SimTables, traffic: Traffic, cfg: SimConfig) -> SimResult:
-    n_active = int(traffic.active.sum())
-    core, fn = _open_loop_runner(tables, traffic, cfg)
-    carry0 = (core.init_queues() + (jax.random.PRNGKey(cfg.seed),
-                                    tel.init_state(cfg.telemetry, core)))
-    carry, stats = fn(carry0, jnp.float32(cfg.injection_rate))
-    snap = tel.snapshot(cfg.telemetry, carry[5], cfg.cycles)
-    return _assemble_result(tables, traffic, cfg, n_active, stats, snap)
+    # host spans on the profiler's clock (inert unless it is tracing):
+    # the dispatch of the compiled scan returns at once, so the wait on
+    # the device falls in `sim.assemble`, where stats reach the host
+    with jax.profiler.TraceAnnotation("sim.simulate", seed=cfg.seed):
+        n_active = int(traffic.active.sum())
+        core, fn = _open_loop_runner(tables, traffic, cfg)
+        with jax.profiler.TraceAnnotation("sim.init_carry"):
+            carry0 = _init_carry(core, cfg.seed)
+        with jax.profiler.TraceAnnotation("sim.scan"):
+            carry, stats = fn(carry0, jnp.float32(cfg.injection_rate))
+        with jax.profiler.TraceAnnotation("sim.assemble"):
+            snap = tel.snapshot(cfg.telemetry, carry[5], cfg.cycles)
+            return _assemble_result(tables, traffic, cfg, n_active, stats,
+                                    snap)

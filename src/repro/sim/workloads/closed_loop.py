@@ -313,17 +313,19 @@ def _space_runner(tables: SimTables, wls: Tuple[Workload, ...],
 
         # ---- ready set over the DAGs (dense mask, carried counters);
         # a message is sendable only once its job has been admitted
-        done = flits_del >= size                            # [M]
-        dep_ok = jnp.where(dep >= 0, done[jnp.maximum(dep, 0)],
-                           True).all(axis=1)
-        admitted = (cycle >= admit)[job_of_msg]             # [M]
-        sendable = dep_ok & (sent < size) & admitted        # [M]
+        with jax.named_scope("closed.ready"):
+            done = flits_del >= size                        # [M]
+            dep_ok = jnp.where(dep >= 0, done[jnp.maximum(dep, 0)],
+                               True).all(axis=1)
+            admitted = (cycle >= admit)[job_of_msg]         # [M]
+            sendable = dep_ok & (sent < size) & admitted    # [M]
 
         # ---- per-endpoint pick: lowest-id sendable message
-        cand = (msgs_by_ep >= 0) & sendable[jnp.maximum(msgs_by_ep, 0)]
-        has = cand.any(axis=1)                              # [n_ep]
-        slot = jnp.argmax(cand, axis=1)
-        mpick = jnp.where(has, msgs_by_ep[eids, slot], 0)
+        with jax.named_scope("closed.pick"):
+            cand = (msgs_by_ep >= 0) & sendable[jnp.maximum(msgs_by_ep, 0)]
+            has = cand.any(axis=1)                          # [n_ep]
+            slot = jnp.argmax(cand, axis=1)
+            mpick = jnp.where(has, msgs_by_ep[eids, slot], 0)
 
         # ---- inject one flit (same source-queue mechanics as open loop)
         want = has & (sq_count < Qs)
@@ -333,9 +335,10 @@ def _space_runner(tables: SimTables, wls: Tuple[Workload, ...],
                               jnp.zeros((n_ep,), jnp.int32), phase,
                               msg=fid[mpick])
         sq_pkt, sq_count = c.inject(sq_pkt, sq_count, want, new_pkt)
-        msel = jnp.where(want, mpick, M)                    # M = OOB drop
-        sent = sent.at[msel].add(1, mode="drop")
-        start_c = start_c.at[msel].min(cycle, mode="drop")
+        with jax.named_scope("closed.account"):
+            msel = jnp.where(want, mpick, M)                # M = OOB drop
+            sent = sent.at[msel].add(1, mode="drop")
+            start_c = start_c.at[msel].min(cycle, mode="drop")
 
         # ---- telemetry at the injection point (data-only)
         extra = None
@@ -356,14 +359,17 @@ def _space_runner(tables: SimTables, wls: Tuple[Workload, ...],
              occ, cycle, fold, (flits_del, jnp.int32(0)),
              tel_state=ts, trace_sample=sampler, trace_extra=extra)
 
-        now_done = flits_del >= size
-        done_c = jnp.where(now_done & (done_c == BIG), cycle + 1, done_c)
-        # per-job done-message counts without a scatter: job segments
-        # are contiguous, so a cumsum difference at the offsets does it
-        ncs = jnp.concatenate([
-            jnp.zeros((1,), jnp.int32),
-            jnp.cumsum(now_done.astype(jnp.int32))])
-        n_done_job = ncs[job_off[1:]] - ncs[job_off[:-1]]   # [J]
+        with jax.named_scope("closed.account"):
+            now_done = flits_del >= size
+            done_c = jnp.where(now_done & (done_c == BIG), cycle + 1,
+                               done_c)
+            # per-job done-message counts without a scatter: job
+            # segments are contiguous, so a cumsum difference at the
+            # offsets does it
+            ncs = jnp.concatenate([
+                jnp.zeros((1,), jnp.int32),
+                jnp.cumsum(now_done.astype(jnp.int32))])
+            n_done_job = ncs[job_off[1:]] - ncs[job_off[:-1]]   # [J]
         stats = (want.sum().astype(jnp.int32), delivered, n_done_job)
         return (nq_pkt, nq_count, sq_pkt, sq_count, admit,
                 sent, flits_del, start_c, done_c, key, ts), stats
@@ -397,6 +403,22 @@ def _space_runner(tables: SimTables, wls: Tuple[Workload, ...],
           (run_chunk_const, run_chunk_ops), space)
     _cache_put(_RUNNER_CACHE, key, (tables, tuple(wls), fn))
     return fn
+
+
+def compiled_runner_hlo() -> list:
+    """Optimised HLO text of each single-lane chunk runner compiled in
+    this process (`run_workload`, `run_jobs`).  Its `op_name` metadata
+    carries the stage scopes (`switch.*`, `closed.*`); see
+    `repro.sim.engine.compiled_runner_hlo`."""
+    out = []
+    for key, (_, _, fn) in list(_RUNNER_CACHE.items()):
+        if isinstance(key[0], str):             # the lane-batched sweeps
+            continue
+        run, init_carry, _, _ = fn
+        carry = jax.eval_shape(lambda: init_carry(jax.random.PRNGKey(0)))
+        offset = jax.ShapeDtypeStruct((), jnp.int32)
+        out.append(run.lower(carry, offset).compile().as_text())
+    return out
 
 
 def _chunk_runner(tables: SimTables, wl: Workload, ep_of_rank: np.ndarray,
@@ -460,24 +482,31 @@ def run_workload(tables: SimTables, wl: Workload,
     ep_of_rank = np.asarray(ep_of_rank, dtype=np.int32)
     run_chunk, init_carry, _ = _chunk_runner(tables, wl, ep_of_rank, cfg)
 
-    carry = init_carry(jax.random.PRNGKey(cfg.seed))
-    M = wl.n_messages
-    per_cycle_dlv = []
-    completed = False
-    t = 0
-    while t < cfg.max_cycles:
-        carry, (inj, dlv, n_done) = run_chunk(carry, jnp.int32(t))
-        per_cycle_dlv.append(np.asarray(dlv, dtype=np.int64))
-        t += cfg.chunk
-        if int(n_done[-1, 0]) == M:
-            completed = True
-            break
+    # host spans on the profiler's clock (inert unless it is tracing):
+    # each chunk's span holds its dispatch and the host's wait for its
+    # per-cycle stats, so the gaps between chunks fall inside them
+    with jax.profiler.TraceAnnotation("workload.run", seed=cfg.seed):
+        with jax.profiler.TraceAnnotation("workload.init_carry"):
+            carry = init_carry(jax.random.PRNGKey(cfg.seed))
+        M = wl.n_messages
+        per_cycle_dlv = []
+        completed = False
+        t = 0
+        while t < cfg.max_cycles:
+            with jax.profiler.TraceAnnotation("workload.chunk", start=t):
+                carry, (inj, dlv, n_done) = run_chunk(carry, jnp.int32(t))
+                per_cycle_dlv.append(np.asarray(dlv, dtype=np.int64))
+                t += cfg.chunk
+                if int(n_done[-1, 0]) == M:
+                    completed = True
+                    break
 
-    (_, _, _, _, _, sent, flits_del, start_c, done_c, _, ts) = carry
-    return _workload_result(wl, cfg, ep_of_rank,
-                            (sent, flits_del, start_c, done_c),
-                            np.concatenate(per_cycle_dlv), completed, t,
-                            tel_state=ts)
+        with jax.profiler.TraceAnnotation("workload.result"):
+            (_, _, _, _, _, sent, flits_del, start_c, done_c, _, ts) = carry
+            return _workload_result(wl, cfg, ep_of_rank,
+                                    (sent, flits_del, start_c, done_c),
+                                    np.concatenate(per_cycle_dlv),
+                                    completed, t, tel_state=ts)
 
 
 def _sweep_run_workload(tables: SimTables, wl: Workload,

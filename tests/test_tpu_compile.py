@@ -14,6 +14,7 @@ imported, and every test of this kind lives in this one file.
 """
 
 import importlib
+import re
 
 import numpy as np
 import pytest
@@ -25,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import alloc_rounds, backend, ugal_select
 
 minplus_mod = importlib.import_module("repro.kernels.minplus")
+alloc_mod = importlib.import_module("repro.kernels.alloc")
 
 # (N routers, P network ports, V VCs, PE endpoints/router, W window)
 SHAPES = {"q5": (50, 7, 4, 4, 4), "q19": (722, 29, 4, 15, 4)}
@@ -114,3 +116,38 @@ def test_minplus_compiles(one_chip):
     x = jax.ShapeDtypeStruct((2, N, N), jnp.float32, sharding=one_chip)
     compiled = _compile(minplus_mod.minplus_pallas, [x, x])
     assert np.prod(compiled.out_info.shape) == 2 * N * N
+
+
+def _kernel_args(one_chip, kernel):
+    N, P, V, PE, W = SHAPES["q5"]
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if kernel == "alloc_rounds":
+        PV = P * V
+        return ([s(), s(N, PV, W), s(N, PV, W), s(N, PV, W), s(N, PV),
+                 s(N, PE, W), s(N, PE, W), s(N, PE, W), s(N, PE), s(N)],
+                dict(W=W, P=P, V=V, PE=PE, p_budget=PE, NQ=N * PV,
+                     R=N * PV + N * PE))
+    E, C = N * PE, 4
+    return ([s(E), s(E, C), s(E), s(E, C)],
+            dict(ugal_g=False, unreach=1 << 14, big=1 << 30))
+
+
+@pytest.mark.parametrize("kernel", ["alloc_rounds", "ugal_select"])
+def test_kernel_op_name_outlives_a_wrapper_rename(one_chip, kernel):
+    """Each `pallas_call` carries `name=`, so the compiled kernel is
+    named `<kernel>_pallas[.<n>]`, the name a profile's reduction finds
+    it by, even where the jitted wrapper around it is renamed."""
+    wrapped = getattr(alloc_mod, f"{kernel}_pallas").__wrapped__
+
+    def renamed_wrapper(*a, **kw):
+        return wrapped(*a, **kw)
+
+    args, kw = _kernel_args(one_chip, kernel)
+    text = _compile(lambda *a: renamed_wrapper(*a, **kw), args).as_text()
+    names = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert len(names) == 1
+    assert re.fullmatch(rf"{kernel}_pallas(\.\d+)?", names[0]), names
