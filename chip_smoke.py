@@ -213,7 +213,7 @@ def phase_d(tables):
 
     from repro.sim.workloads import (WorkloadSimConfig, place_ranks,
                                      ring_all_reduce, run_workload)
-    from repro.sim.workloads.closed_loop import _chunk_runner
+    from repro.sim.workloads.closed_loop import _space_runner
 
     wl = ring_all_reduce(128, 4)
     cfg = WorkloadSimConfig(mode="min", placement="spread", seed=0)
@@ -221,7 +221,8 @@ def phase_d(tables):
     for path in ("auto", "ref"):
         c = dataclasses.replace(cfg, kernel_path=path)
         ep = place_ranks(tables, wl.n_ranks, c.placement, seed=c.seed)
-        run_chunk, init_carry, _ = _chunk_runner(tables, wl, ep, c)
+        run_chunk, init_carry, _, _ = _space_runner(
+            tables, (wl,), (np.asarray(ep, np.int32),), c)
         comp = compile_step("closed loop", path, run_chunk,
                             init_carry(jax.random.PRNGKey(c.seed)),
                             jnp.int32(0))

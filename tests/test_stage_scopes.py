@@ -52,14 +52,17 @@ def test_open_loop_runner_names_every_stage(q5, monkeypatch):
         SWITCH | {"switch.telemetry"})
 
 
-@pytest.mark.parametrize("mode,drop", [
-    ("ugal_l", set()),
+@pytest.mark.parametrize("mode,drop,ranks", [
+    ("ugal_l", set(), 8),
     # MIN reads no occupancy and chooses no route: both compile away
-    ("min", {"switch.occupancy", "switch.route"})])
-def test_closed_loop_runner_names_every_stage(q5, monkeypatch, mode, drop):
+    ("min", {"switch.occupancy", "switch.route"}, 8),
+    # every endpoint sends, so the pick scans all of them
+    ("min", {"switch.occupancy", "switch.route"}, 150)])
+def test_closed_loop_runner_names_every_stage(q5, monkeypatch, mode, drop,
+                                              ranks):
     monkeypatch.setattr(closed_loop, "_RUNNER_CACHE", {})
     tables, _ = q5
-    run_workload(tables, ring_all_reduce(8, 2), WorkloadSimConfig(
+    run_workload(tables, ring_all_reduce(ranks, 2), WorkloadSimConfig(
         mode=mode, placement="spread", chunk=4, max_cycles=8))
     assert stages_of(closed_loop.compiled_runner_hlo()) == (
         (SWITCH - drop) | CLOSED)
@@ -122,7 +125,8 @@ def test_entry_points_write_host_spans(q5, tmp_path):
             and by["sim.scan"][0][2] <= by["sim.assemble"][0][1])
 
     (run,) = by["workload.run"]
-    assert run[3] == {"seed": 5}
+    # one rank on each of 16 routers, 2 x 15 ring steps each
+    assert run[3] == {"seed": 5, "pick_rows": 16, "pick_width": 30}
     chunks = sorted(by["workload.chunk"], key=lambda s: s[1])
     assert res.cycles_run == 8
     assert [c[3] for c in chunks] == [{"start": 0}, {"start": 4}]

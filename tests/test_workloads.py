@@ -6,6 +6,8 @@ criterion pins at 2x."""
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,7 +29,13 @@ from repro.sim.workloads import (
     stencil,
     summarize,
 )
-from repro.sim.workloads.closed_loop import WorkloadResult
+from repro.sim.workloads.closed_loop import (
+    WorkloadResult,
+    _build_space,
+    _pick,
+    _pick_rows,
+)
+from repro.sim.workloads.jobs import Job, place_jobs
 
 RING_K, RING_CHUNK = 16, 8
 
@@ -250,6 +258,69 @@ def test_closed_loop_deterministic(sf5_tables, ring_run):
     r2 = run_workload(sf5_tables, wl, cfg)
     assert r1.makespan == r2.makespan
     np.testing.assert_array_equal(r1.msg_done, r2.msg_done)
+
+
+def _lowest_sendable(src_ep, sendable, n_ep):
+    """Each endpoint's lowest-id sendable message, by a plain loop."""
+    has = np.zeros(n_ep, bool)
+    mpick = np.zeros(n_ep, np.int32)
+    for m in range(len(src_ep) - 1, -1, -1):
+        if sendable[m]:
+            has[src_ep[m]], mpick[src_ep[m]] = True, m
+    return has, mpick
+
+
+def _pick_space(tables, case):
+    if case == "ring_8_of_150":
+        wls = (ring_all_reduce(8, 2),)
+        eps = (place_ranks(tables, 8, "spread"),)
+    elif case == "every_endpoint":
+        wls = (ring_all_reduce(tables.n_endpoints, 1),)
+        eps = (place_ranks(tables, tables.n_endpoints, "linear"),)
+    elif case == "two_jobs":
+        jobs = [Job("ring", ring_all_reduce(8, 2)),
+                Job("a2a", all_to_all(6, 1), arrival=3)]
+        wls = tuple(j.workload for j in jobs)
+        eps = tuple(place_jobs(tables, jobs, "spread"))
+    else:                                       # ragged rows
+        wls = (graph_scatter(24, 1, iters=2, seed=3),)
+        eps = (place_ranks(tables, 24, "random", seed=4),)
+    return wls, tuple(np.asarray(e, np.int32) for e in eps)
+
+
+@pytest.mark.parametrize("case", ["ring_8_of_150", "every_endpoint",
+                                  "two_jobs", "graph_scatter"])
+def test_pick_is_lowest_sendable_message(sf5_tables, case):
+    """The closed loop's pick scans only the endpoints that own a
+    message, and answers as a dense scan over every endpoint would."""
+    n_ep = sf5_tables.n_endpoints
+    wls, eps = _pick_space(sf5_tables, case)
+    space = _build_space(wls, eps)
+    msgs, row = _pick_rows(space.src_ep, n_ep)
+
+    senders = np.unique(np.concatenate(
+        [ep[wl.src] for wl, ep in zip(wls, eps)]))
+    n_act, kmax = msgs.shape
+    assert n_act == len(senders)
+    assert kmax == np.bincount(space.src_ep).max()
+    if case == "every_endpoint":
+        assert n_act == n_ep
+    else:
+        assert n_act < n_ep
+    np.testing.assert_array_equal(np.flatnonzero(row < n_act), senders)
+    if case == "graph_scatter":
+        assert np.bincount(space.src_ep)[senders].min() < kmax
+
+    run = jax.jit(lambda s: _pick(jnp.asarray(msgs), jnp.asarray(row), s))
+    rng = np.random.default_rng(0)
+    M = space.n_messages
+    masks = [np.zeros(M, bool), np.ones(M, bool)] + [
+        rng.random(M) < p for p in (0.02, 0.3, 0.7)]
+    for sendable in masks:
+        has, mpick = run(jnp.asarray(sendable))
+        want_has, want_pick = _lowest_sendable(space.src_ep, sendable, n_ep)
+        np.testing.assert_array_equal(np.asarray(has), want_has)
+        np.testing.assert_array_equal(np.asarray(mpick), want_pick)
 
 
 # ---------------------------------------------------------------------------
