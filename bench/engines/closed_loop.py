@@ -1,5 +1,8 @@
 """Closed-loop engine: one call is one `repro.sim.workloads.run_workload`
-of a collective, cut at the mix's `max_cycles`.
+of a collective, cut at the mix's `max_cycles`.  The collective is the
+program's `repro.sim.workloads.<collective>(**args)` and the reference's
+`bench/reference/collectives/<collective>.py`, both given the mix's
+`args`.
 
 The tables and the workload are built once; every call reuses them, so
 after the warm-up every chunk hits the simulator's compiled runner.
@@ -14,7 +17,7 @@ import dataclasses
 import numpy as np
 
 from bench.engines.open_loop import sizes  # noqa: F401 (the harness calls it)
-from bench.engines.open_loop import topology
+from bench.engines.open_loop import tables
 from bench.reference import fabric as ref_fabric
 from bench.reference import runs as ref_runs
 from bench.reference.network import Switch
@@ -30,18 +33,14 @@ class State:
 
 
 def build_workload(mix: dict):
-    from repro.sim.workloads import ring_all_reduce
+    import repro.sim.workloads
 
-    if mix["collective"] != "ring_all_reduce":
-        raise ValueError(f"no collective {mix['collective']!r}")
-    return ring_all_reduce(int(mix["ranks"]), int(mix["flits_per_step"]))
+    return getattr(repro.sim.workloads, mix["collective"])(**mix["args"])
 
 
 def setup(config: dict, mix: dict) -> State:
-    from repro.sim import SimTables
     from repro.sim.workloads import WorkloadSimConfig
 
-    tables = SimTables.build(topology(config["topology"]))
     sw = config["switch"]
     cfg = WorkloadSimConfig(mode=mix["mode"], placement=mix["placement"],
                             chunk=int(mix["chunk"]),
@@ -49,7 +48,7 @@ def setup(config: dict, mix: dict) -> State:
                             vcs=sw["vcs"], q_net=sw["q_net"],
                             q_src=sw["q_src"], lookahead=sw["lookahead"],
                             n_val_candidates=sw["n_val_candidates"])
-    return State(config, mix, tables, build_workload(mix), cfg)
+    return State(config, mix, tables(config, mix), build_workload(mix), cfg)
 
 
 def uses_pallas(state: State) -> bool:
@@ -99,9 +98,9 @@ def reference(state: State, seed: int, control: bool = False) -> dict:
     return ref_runs.closed_loop(
         ref_fabric.build(state.config["topology"]),
         Switch(**state.config["switch"]), kind=mix["collective"],
-        n_ranks=int(mix["ranks"]), flits=int(mix["flits_per_step"]),
-        placement=mix["placement"], mode=mix["mode"], chunk=cfg.chunk,
-        max_cycles=cfg.max_cycles, stale_deps=1 if control else 0)
+        args=mix["args"], placement=mix["placement"], mode=mix["mode"],
+        chunk=cfg.chunk, max_cycles=cfg.max_cycles,
+        stale_deps=1 if control else 0)
 
 
 def parts(got: dict, want: dict) -> dict:

@@ -1,17 +1,31 @@
 """Run one benchmark cell once: set-up, the measured window, the check
 against the plain reference, and one result line.
 
-Everything that belongs to one configuration, traffic mix, engine or
-per-layer metric sits in a file of its own, found by name:
+Everything that belongs to one configuration, traffic mix, engine,
+fabric family, routing mode, collective or per-layer metric sits in a
+file of its own, found by name, so that a new one arrives as a new file
+and an entry in `BENCHMARK.json`:
 
 - `BENCHMARK.json` names the cells (configuration x traffic mix) and
   the metrics;
-- a configuration is the JSON file its entry names;
+- a configuration is the JSON file its entry names; its
+  `topology.family` is built by the program's `build_<family>` (in
+  `repro.core` or `repro.core.topologies`) and by the reference's
+  `bench/reference/families/<family>.py`, both given the entry's other
+  keys;
 - a traffic mix is `bench/traffic/<mix>.json`; its `engine` key names
   `bench/engines/<engine>.py`, which builds the system under test once
   (`setup`), makes one call of its public entry point (`call`), counts
   the work of a call (`router_cycles`) and checks an answer against the
-  plain reference (`observe`, `reference`, `compare`);
+  plain reference (`observe`, `reference`, `compare`).  An engine
+  reports the rate `RATE` where it declares one, else
+  `<engine>.router_cycles_per_s`;
+- a mix's `mode` is the program's routing mode and the reference's
+  `bench/reference/modes/<mode>.py`; a closed-loop mix's `collective`
+  is `repro.sim.workloads.<collective>` and the reference's
+  `bench/reference/collectives/<collective>.py`, both given the mix's
+  `args`; an open-loop mix with `rates` runs one lane per rate in one
+  call, and the check compares one lane, drawn from the call's seed;
 - a per-layer metric `<name>` is read by `bench/metrics/<name>.py`, or,
   for a name `<base>.<engine tag>`, by `bench/metrics/<base>.py`; its
   `read(ctx)` returns a number, or None when the run has nothing it
@@ -200,7 +214,8 @@ def traced_call(engine, state, seed: int, trace_dir: str) -> dict:
 
 def check(engine, state, window: dict, seed: int) -> list:
     """Compare one call of the window, drawn from the seed, with the plain
-    reference.  Returns [(name, value, limit)]."""
+    reference.  Returns [(name, value, limit[, where])], `where` a dict
+    that says which part of the answer was compared."""
     import numpy as np
 
     calls = window["calls"]
@@ -209,6 +224,12 @@ def check(engine, state, window: dict, seed: int) -> list:
     log(f"bench: checked call seed {pick['seed']}: "
         f"{engine.parts(pick['answer'], want)}")
     return engine.compare(pick["answer"], want)
+
+
+def rate_metric(cell: Cell) -> str:
+    """The end-to-end rate the cell's engine reports."""
+    return getattr(cell.engine, "RATE",
+                   f"{cell.mix['engine']}.router_cycles_per_s")
 
 
 def memory_line(devs: list) -> str:
@@ -261,11 +282,14 @@ def main(argv=None, root: str = ROOT, t_start: float = None) -> int:
     counter.on = True
     trace_summary = None
     if args.trace:
+        from bench import stages
         from bench import trace as trace_mod
 
         with tempfile.TemporaryDirectory() as d:
             window = traced_call(engine, state, args.seed, d)
-            trace_summary = trace_mod.reduce_dir(d)
+            profile = trace_mod.load_profile(trace_mod.find_profile(d))
+            trace_summary = trace_mod.reduce_planes(profile.planes)
+            idle_spans = stages.idle_by_span(profile.planes)
     else:
         window = measure(engine, state, args.seed, args.seconds)
     counter.on = False
@@ -287,12 +311,14 @@ def main(argv=None, root: str = ROOT, t_start: float = None) -> int:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         device["busy_s"] = trace_summary.busy_s
         device["window_s"] = trace_summary.window_s
+        stage_s = collections.Counter(ctx.get("stage_seconds", {}))
         breakdown = {"device_ops": trace_summary.top_ops(10),
-                     "idle_gaps": trace_summary.top_gaps(10)}
+                     "idle_gaps": trace_summary.top_gaps(10),
+                     "stages": [list(kv) for kv in stage_s.most_common(10)],
+                     "idle_by_span": idle_spans[:10]}
     else:
         taken = {"setup_s": setup_s,
-                 f"{cell.mix['engine']}.router_cycles_per_s":
-                     work / window["seconds"]}
+                 rate_metric(cell): work / window["seconds"]}
         for m in cell.end_to_end():
             if m["name"] not in taken:
                 raise SystemExit(f"bench: the harness takes no {m['name']!r}")
@@ -300,18 +326,21 @@ def main(argv=None, root: str = ROOT, t_start: float = None) -> int:
                                   "unit": m["unit"]}
 
     t = time.perf_counter()
-    numbers = check(engine, state, window, args.seed)
+    numbers = [(name, v, limit, where[0] if where else {}) for
+               name, v, limit, *where in check(engine, state, window,
+                                               args.seed)]
     log(f"bench: reference check {time.perf_counter() - t!r} s")
-    correct = all(v <= limit for _, v, limit in numbers)
+    correct = all(v <= limit for _, v, limit, _ in numbers)
     out = {"correct": correct, "attempted": n_calls,
            "failed": 0 if correct else 1, "metrics": metrics,
            "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
-    out["checks"] = {name: {"value": v, "limit": limit}
-                     for name, v, limit in numbers}
-    for name, v, limit in numbers:
-        print(f"check {name} = {v} (limit {limit})", file=sys.stderr)
+    out["checks"] = {name: {"value": v, "limit": limit, **where}
+                     for name, v, limit, where in numbers}
+    for name, v, limit, where in numbers:
+        at = "".join(f" {k} {x}" for k, x in where.items())
+        print(f"check {name} = {v} (limit {limit}){at}", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0

@@ -17,9 +17,9 @@ engine.py's module docstring), written from that description alone:
   up to p ejections per router per cycle, ranked over the network
   queues in request order rotated to start at column (cycle mod P*V),
   with the source queues ranked after them on even cycles and before
-  them on odd ones.  Other packets ask for the output port of their
-  minimal route (toward the Valiant intermediate until they reach it)
-  and are eligible when the downstream FIFO had a free slot at the
+  them on odd ones.  Other packets ask for the output port the mode
+  gives at this hop (toward the Valiant intermediate until they reach
+  it) and are eligible when the downstream FIFO had a free slot at the
   start of the cycle; each output port grants the eligible request with
   the lowest rotating priority (global queue id + 7919*cycle + 131*w)
   mod R, one packet per port per cycle;
@@ -27,9 +27,11 @@ engine.py's module docstring), written from that description alone:
   of the rest kept) and joins the tail of the downstream FIFO of its
   VC; its hop count rises by one, and it enters its second phase on
   reaching its intermediate.
-- UGAL-L scores the minimal route against C random Valiant
-  intermediates by hops times the depth of the first output queue, and
-  keeps the minimal route on ties.
+- the routing mode is a file of its own (`modes/<mode>.py`): it picks
+  each new packet's intermediate at injection and may pick the output
+  port at each hop (the minimal first port otherwise);
+- a router's source queues are those of its endpoints, in id order; a
+  router without endpoints has none.
 
 Random draws are made with jax.random from the run's seed, split per
 cycle into (next key, injection, destination, route) keys as the
@@ -42,8 +44,10 @@ import dataclasses
 
 import numpy as np
 
-OCC_CAP = 1 << 20          # occupancy cap in UGAL scores
+from . import by_name
+
 PRIO_CYCLE, PRIO_ROUND = 7919, 131
+UNUSED_PORT_OCC = 1 << 30  # credit view of a port with no link
 HOPS_MAX = 63
 
 
@@ -86,8 +90,9 @@ class Packets:
 class Network:
     """Queues of packet ids and one `cycle` of the switch pipeline."""
 
-    def __init__(self, fab, sw: Switch, capacity: int = 1 << 16):
+    def __init__(self, fab, sw: Switch, mode: str, capacity: int = 1 << 16):
         self.fab, self.sw = fab, sw
+        self.mode = by_name("modes", mode)
         N, P, V = fab.n_routers, fab.n_ports, sw.vcs
         self.N, self.P, self.V, self.E = N, P, V, fab.n_endpoints
         self.nq = np.full((N, P, V, sw.q_net), -1, np.int64)
@@ -102,35 +107,21 @@ class Network:
     # -- credit view -------------------------------------------------------
     def occupancy(self) -> np.ndarray:
         f = self.fab
-        return self.ncount[f.nbr, f.rev, :].sum(axis=-1)          # [N, P]
+        occ = self.ncount[np.maximum(f.nbr, 0), np.maximum(f.rev, 0),
+                          :].sum(axis=-1)                           # [N, P]
+        return np.where(f.nbr >= 0, occ, UNUSED_PORT_OCC)
 
     # -- routing -----------------------------------------------------------
-    def route(self, mode, src_r, dst_r, occ, cands):
-        """(inter, phase) of new packets: MIN, or UGAL-L over `cands`."""
-        if mode == "min":
-            return dst_r.copy(), np.ones_like(dst_r)
-        if mode != "ugal_l":
-            raise ValueError(f"no reference for routing mode {mode!r}")
-        N, f = self.N, self.fab
-        c = cands.astype(np.int64)
-        for bump in (1, 2):
-            bad = (c == src_r[:, None]) | (c == dst_r[:, None])
-            c = np.where(bad, (c + bump) % N, c)
+    def route(self, src_r, dst_r, occ, draws):
+        """(inter, phase) of new packets, by the mode's injection hook."""
+        return self.mode.route(self, src_r, dst_r, occ, draws)
 
-        def first_occ(s, t):
-            o = f.port_toward[s, t]
-            return np.where(o >= 0,
-                            np.minimum(occ[s, np.maximum(o, 0)], OCC_CAP), 0)
-
-        score_min = f.dist[src_r, dst_r] * first_occ(src_r, dst_r)
-        s2 = np.broadcast_to(src_r[:, None], c.shape)
-        score_val = ((f.dist[s2, c] + f.dist[c, dst_r[:, None]])
-                     * first_occ(s2, c))
-        scores = np.concatenate([score_min[:, None], score_val], axis=1)
-        best = scores.argmin(axis=1)                 # first minimum: MIN on ties
-        inter = np.where(best == 0, dst_r,
-                         c[np.arange(len(c)), np.maximum(best - 1, 0)])
-        return inter, (best == 0).astype(np.int64)
+    def hop(self, r, tgt, occ):
+        """Output port at router `r` toward `tgt` (-1 at the target)."""
+        hop = getattr(self.mode, "hop", None)
+        if hop is None:
+            return self.fab.port_toward[r, tgt]
+        return hop(self, r, tgt, occ)
 
     def inject(self, want, dst_r, inter, phase, cycle, msg=None):
         e = np.nonzero(want)[0]
@@ -149,16 +140,23 @@ class Network:
         K = PV + p
         Qn = sw.q_net
 
-        # requests of router r: its P*V network queues, then its p
-        # endpoints' source queues; window slot w of each
+        # requests of router r: its P*V network queues, then the source
+        # queues of its endpoint slots (`ep_at`, -1: no endpoint, never
+        # occupied); window slot w of each
         ncount0 = self.ncount.copy()                       # cycle start
+        occ = self.occupancy()
+        ep_at = f.ep_at
+        has_ep = ep_at >= 0
+        e_at = np.maximum(ep_at, 0)
         cnt = np.concatenate([ncount0.reshape(N, PV),
-                              self.scount.reshape(N, p)], axis=1)  # [N, K]
+                              np.where(has_ep, self.scount[e_at], 0)],
+                             axis=1)                        # [N, K]
         pad = max(0, W - Qn)
         win = np.concatenate([
             np.pad(self.nq[..., :W], ((0, 0),) * 3 + ((0, pad),),
                    constant_values=-1).reshape(N, PV, W),
-            self.sq[:, :W].reshape(N, p, W)], axis=1)       # [N, K, W] ids
+            np.where(has_ep[..., None], self.sq[e_at, :W], -1)],
+            axis=1)                                         # [N, K, W] ids
         # desires of the occupied window slots (the rest are never asked)
         vr, vk, vw = np.nonzero(cnt[:, :, None] > np.arange(W))
         ids = win[vr, vk, vw]
@@ -167,7 +165,7 @@ class Network:
         eject = np.zeros((N, K, W), bool)
         eject[vr, vk, vw] = (dst == vr) & (phase == 1)
         out = np.full((N, K, W), -1)
-        out[vr, vk, vw] = f.port_toward[vr, tgt]            # -1 at the target
+        out[vr, vk, vw] = self.hop(vr, tgt, occ)            # -1 at the target
         vc = np.zeros((N, K, W), np.int64)
         vc[vr, vk, vw] = np.minimum(pk.hops[ids], V - 1)
         o = np.maximum(out[vr, vk, vw], 0)
@@ -177,8 +175,7 @@ class Network:
 
         qid = np.concatenate([
             np.arange(N)[:, None] * PV + np.arange(PV)[None, :],
-            self.NQ + np.arange(N)[:, None] * p + np.arange(p)[None, :]],
-            axis=1)                                         # [N, K]
+            self.NQ + ep_at], axis=1)                       # [N, K]
         rot0 = (qid + cycle * PRIO_CYCLE) % self.R
         free = np.ones((N, K), bool)
         chan_free = np.ones((N, P), bool)
@@ -244,7 +241,7 @@ class Network:
         self._remove(self.nq.reshape(N * PV, Qn), self.ncount.reshape(-1),
                      gr[net] * PV + gk[net], slot[net])
         self._remove(self.sq, self.scount,
-                     gr[~net] * p + (gk[~net] - PV), slot[~net])
+                     ep_at[gr[~net], gk[~net] - PV], slot[~net])
         # arrivals after the dequeue, at the new tail
         tail = self.ncount[nr, nport, vc_c]
         self.nq[nr, nport, vc_c, tail] = moving

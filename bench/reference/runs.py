@@ -6,15 +6,18 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import by_name
 from .network import Network, Switch
 
 BIG = 1 << 30
 
 
 def open_loop_draws(seed: int, cycles: int, n_ep: int, n_routers: int,
-                    rate: float, n_cand: int) -> tuple:
-    """Per-cycle (injection coins, destination draws, Valiant candidates)
-    from jax.random: key -> (key, k_inj, k_dst, k_route) each cycle."""
+                    rate: float, draw_route=None) -> tuple:
+    """Per-cycle (injection coins, destination draws, route draws) from
+    jax.random: key -> (key, k_inj, k_dst, k_route) each cycle.  The
+    route draws are the mode's `draw(k_route, n_ep, n_routers)`, or
+    None for a mode that draws nothing."""
     import jax
     import jax.numpy as jnp
 
@@ -25,11 +28,13 @@ def open_loop_draws(seed: int, cycles: int, n_ep: int, n_routers: int,
             return key, (jax.random.bernoulli(k_inj, jnp.float32(rate),
                                               (n_ep,)),
                          jax.random.randint(k_dst, (n_ep,), 0, n_ep - 1),
-                         jax.random.randint(k_rt, (n_ep, n_cand), 0,
-                                            n_routers))
+                         None if draw_route is None
+                         else draw_route(k_rt, n_ep, n_routers))
         return jax.lax.scan(step, key, None, length=cycles)[1]
 
-    return tuple(np.asarray(a) for a in draws(jax.random.PRNGKey(seed)))
+    coins, dsts, route = draws(jax.random.PRNGKey(seed))
+    return (np.asarray(coins), np.asarray(dsts),
+            [None] * cycles if route is None else np.asarray(route))
 
 
 def open_loop(fab, sw: Switch, *, pattern: str, rate: float, mode: str,
@@ -42,11 +47,13 @@ def open_loop(fab, sw: Switch, *, pattern: str, rate: float, mode: str,
     `latency_dtype` is the precision the latency sum is kept in."""
     if pattern != "uniform":
         raise ValueError(f"no reference for traffic pattern {pattern!r}")
-    net = Network(fab, sw, capacity=max(1, int(rate * fab.n_endpoints
-                                               * cycles * 1.2)))
+    net = Network(fab, sw, mode, capacity=max(1, int(rate * fab.n_endpoints
+                                                     * cycles * 1.2)))
     E, N = net.E, net.N
-    coins, dsts, cands = open_loop_draws(seed, cycles, E, N, rate,
-                                         sw.n_val_candidates)
+    draw = getattr(net.mode, "draw", None)
+    coins, dsts, route = open_loop_draws(
+        seed, cycles, E, N, rate,
+        None if draw is None else lambda k, e, n: draw(k, e, n, sw))
     src_r = net.ep_router
     per = {k: np.zeros(cycles, np.int64) for k in
            ("injected", "delivered", "src_backlog", "dropped", "in_flight")}
@@ -58,7 +65,7 @@ def open_loop(fab, sw: Switch, *, pattern: str, rate: float, mode: str,
         dropped = int((coins[c] & (net.scount >= sw.q_src)).sum())
         d = dsts[c].astype(np.int64)
         dst_r = src_r[np.where(d >= eid, d + 1, d)]
-        inter, phase = net.route(mode, src_r, dst_r, occ, cands[c])
+        inter, phase = net.route(src_r, dst_r, occ, route[c])
         net.inject(want, dst_r, inter, phase, c)
         lat = [latency_dtype(0)]
         got = [0]
@@ -87,36 +94,24 @@ def open_loop(fab, sw: Switch, *, pattern: str, rate: float, mode: str,
     return {"per_cycle": per, "summary": summary}
 
 
-def collective(kind: str, n_ranks: int, flits: int) -> dict:
-    """Messages of a ring all-reduce: src/dst ranks, sizes, dependencies.
-
-    2(k-1) steps; at step s rank r sends one chunk to rank r+1, once the
-    chunk it received at step s-1 from rank r-1 is delivered."""
-    if kind != "ring_all_reduce":
-        raise ValueError(f"no reference for collective {kind!r}")
-    k = n_ranks
-    src, dst, dep, phase = [], [], [], []
-    for s in range(2 * (k - 1)):                 # reduce-scatter, gather
-        for r in range(k):
-            src.append(r)
-            dst.append((r + 1) % k)
-            dep.append(-1 if s == 0 else (s - 1) * k + (r - 1) % k)
-            phase.append(0 if s < k - 1 else 1)
-    return dict(src=np.array(src), dst=np.array(dst),
-                size=np.full(len(src), flits, np.int64),
-                dep=np.array(dep)[:, None], phase=np.array(phase))
+def collective(kind: str, args: dict) -> dict:
+    """Messages of a collective (`collectives/<kind>.py`): its rank
+    count, and the src/dst ranks, sizes, dependencies [M, D] (-1 none)
+    and phases of its messages."""
+    return by_name("collectives", kind).messages(**args)
 
 
 def place(fab, n_ranks: int, placement: str) -> np.ndarray:
     """Endpoint of each rank: `spread` deals ranks round-robin over the
-    routers, first endpoints first."""
+    routers that hold endpoints, first endpoints first."""
     if placement != "spread":
         raise ValueError(f"no reference for placement {placement!r}")
+    routers = np.unique(fab.ep_router)
     i = np.arange(n_ranks)
-    return (i % fab.n_routers) * fab.p + i // fab.n_routers
+    return fab.ep_at[routers[i % len(routers)], i // len(routers)]
 
 
-def closed_loop(fab, sw: Switch, *, kind: str, n_ranks: int, flits: int,
+def closed_loop(fab, sw: Switch, *, kind: str, args: dict,
                 placement: str, mode: str, chunk: int,
                 max_cycles: int, stale_deps: int = 0) -> dict:
     """Dependency-triggered run of a collective, in chunks of `chunk`
@@ -125,16 +120,15 @@ def closed_loop(fab, sw: Switch, *, kind: str, n_ranks: int, flits: int,
 
     Each cycle every endpoint injects one flit of its lowest-numbered
     message whose dependencies are all delivered and that has flits
-    left.  `stale_deps` > 0 reads the done state that many cycles late
-    (the control that breaks the dependency guarantee)."""
-    if mode != "min":
-        raise ValueError("the closed-loop reference routes MIN only")
-    wl = collective(kind, n_ranks, flits)
-    ep_of_rank = place(fab, n_ranks, placement)
+    left, routed by the mode with no route draws.  `stale_deps` > 0
+    reads the done state that many cycles late (the control that breaks
+    the dependency guarantee)."""
+    wl = collective(kind, args)
+    ep_of_rank = place(fab, wl["n_ranks"], placement)
     src_ep, dst_ep = ep_of_rank[wl["src"]], ep_of_rank[wl["dst"]]
     size, dep = wl["size"], wl["dep"]
     M = len(size)
-    net = Network(fab, sw, capacity=int(size.sum()) + 1)
+    net = Network(fab, sw, mode, capacity=int(size.sum()) + 1)
     E = net.E
     # messages of each sending endpoint, ascending id
     senders, col = np.unique(src_ep, return_inverse=True)
@@ -167,8 +161,9 @@ def closed_loop(fab, sw: Switch, *, kind: str, n_ranks: int, flits: int,
                 has[senders],
                 by_ep[np.arange(len(senders)), cand.argmax(axis=1)], 0)
             want = has & (net.scount < sw.q_src)
-            net.inject(want, dst_r[pick], dst_r[pick],
-                       np.ones(E, np.int64), c, msg=pick)
+            inter, phase = net.route(net.ep_router, dst_r[pick],
+                                     net.occupancy(), None)
+            net.inject(want, dst_r[pick], inter, phase, c, msg=pick)
             m = pick[want]
             sent[m] += 1
             start[m] = np.minimum(start[m], c)

@@ -22,14 +22,18 @@ SF_Q5 = {
                "n_val_candidates": 4}}
 MIXES = {
     "uniform_short": {"engine": "open_loop", "pattern": "uniform",
-                      "injection_rate": 0.5, "mode": "ugal_l", "lanes": 1,
+                      "injection_rate": 0.5, "mode": "ugal_l",
+                      "cycles": 24, "warmup": 8, "reduced": {}},
+    "uniform_lanes": {"engine": "open_loop", "pattern": "uniform",
+                      "rates": [0.1, 0.3, 0.5, 0.7, 0.9], "mode": "ugal_l",
                       "cycles": 24, "warmup": 8, "reduced": {}},
     "ring_short": {"engine": "closed_loop", "collective": "ring_all_reduce",
-                   "ranks": 16, "flits_per_step": 4, "mode": "min",
+                   "args": {"n_ranks": 16, "chunk_flits": 4}, "mode": "min",
                    "placement": "spread", "chunk": 16, "max_cycles": 32,
                    "reduced": {}},
 }
 CELLS = {"sf_q5.uniform_short": ("uniform_short", "open_loop"),
+         "sf_q5.uniform_lanes": ("uniform_lanes", "open_loop"),
          "sf_q5.ring_short": ("ring_short", "closed_loop")}
 
 
@@ -42,7 +46,8 @@ def write_json(path, obj):
 @pytest.fixture
 def checkout(tmp_path):
     """A checkout holding a copy of the benchmark's code, with the q=5
-    configuration, two short mixes and their cells added as new files."""
+    configuration, three short mixes and their cells added as new
+    files."""
     shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:

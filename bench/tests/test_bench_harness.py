@@ -9,20 +9,20 @@ import sys
 
 import pytest
 
-from bench.tests.conftest import ROOT
+from bench.tests.conftest import CELLS, ROOT
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                  "checks"}
 
 
-@pytest.mark.parametrize("cell", ["sf_q5.uniform_short", "sf_q5.ring_short"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_runs_and_is_correct(checkout, run_cell, cell):
     line, out = run_cell(checkout, cell)
     assert set(line) == CONTRACT_KEYS
     assert list(line)[-1] == "checks"
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
-    engine = "open_loop" if "uniform" in cell else "closed_loop"
+    engine = CELLS[cell][1]
     assert set(line["metrics"]) == {f"{engine}.router_cycles_per_s",
                                      "setup_s"}
     assert all(m["value"] > 0 for m in line["metrics"].values())
@@ -33,6 +33,12 @@ def test_cell_runs_and_is_correct(checkout, run_cell, cell):
     # each compared number is printed beside its limit, last on stderr
     tail = out.err.strip().splitlines()[-len(line["checks"]):]
     assert all(t.startswith("check ") and "(limit 0)" in t for t in tail)
+    if "lanes" in cell:
+        # the lane drawn from the call's seed is named, with its rate
+        (c,) = line["checks"].values()
+        assert 0 <= c["lane"] < 5
+        assert c["injection_rate"] == [0.1, 0.3, 0.5, 0.7, 0.9][c["lane"]]
+        assert f"lane {c['lane']} " in tail[-1]
     assert "0 compilations inside the window" in out.out
 
 
@@ -55,7 +61,8 @@ def test_new_metric_is_found_by_name(checkout, run_cell):
     assert line["metrics"]["calls_in_window.open"] == {"value": 1,
                                                        "unit": "calls"}
     assert {"busy_s", "window_s"} <= set(line["device"])
-    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps", "stages",
+                                      "idle_by_span"}
 
 
 def test_no_tpu_exits_nonzero_without_a_result():

@@ -33,10 +33,14 @@ def test_fabric_matches_the_simulator_tables(spec):
 
 def test_paper_sizes():
     """q=19 by its published numbers, without building the routes."""
-    adj, p = fabric.slimfly_adjacency(19)
-    assert adj.shape[0] == 722 and (adj.sum(1) == 29).all() and p == 15
-    adj, p = fabric.dragonfly_adjacency(7)
-    assert adj.shape[0] == 1386 and (adj.sum(1) == 20).all() and p == 7
+    from bench.reference.families import dragonfly, slimfly
+
+    adj, ep_router = slimfly.build(q=19)
+    assert adj.shape[0] == 722 and (adj.sum(1) == 29).all()
+    assert (np.bincount(ep_router) == 15).all() and len(ep_router) == 10830
+    adj, ep_router = dragonfly.build(h=7)
+    assert adj.shape[0] == 1386 and (adj.sum(1) == 20).all()
+    assert (np.bincount(ep_router) == 7).all() and len(ep_router) == 9702
 
 
 @pytest.mark.parametrize("mode,rate", [("min", 0.9), ("ugal_l", 0.4)])
@@ -69,7 +73,8 @@ def test_ring_to_completion_matches_run_workload():
                      WorkloadSimConfig(placement="spread", chunk=32,
                                        max_cycles=512))
     ref = runs.closed_loop(fabric.build({"family": "slimfly", "q": 5}), SW,
-                           kind="ring_all_reduce", n_ranks=16, flits=4,
+                           kind="ring_all_reduce",
+                           args={"n_ranks": 16, "chunk_flits": 4},
                            placement="spread", mode="min", chunk=32,
                            max_cycles=512)
     assert r.completed and ref["completed"]
@@ -109,8 +114,9 @@ def test_cells_switch_matches_at_q5(config):
                      WorkloadSimConfig(placement="spread", chunk=32,
                                        max_cycles=96, **sw))
     ref = runs.closed_loop(f5, Switch(**sw), kind="ring_all_reduce",
-                           n_ranks=16, flits=16, placement="spread",
-                           mode="min", chunk=32, max_cycles=96)
+                           args={"n_ranks": 16, "chunk_flits": 16},
+                           placement="spread", mode="min", chunk=32,
+                           max_cycles=96)
     assert (w.msg_done >= 0).any()
     assert np.array_equal(w.msg_done, ref["msg_done"])
     assert np.array_equal(w.per_cycle_delivered, ref["per_cycle_delivered"])

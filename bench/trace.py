@@ -153,22 +153,28 @@ def reduce_planes(planes) -> Summary:
                    busy_ns=busy_ns, gaps=gaps)
 
 
-def reduce_file(path: str) -> Summary:
-    """Reduce an `.xplane.pb` file, or its gzip (`.xplane.pb.gz`)."""
+def load_profile(path: str):
+    """The `jax.profiler.ProfileData` of an `.xplane.pb` file, or of its
+    gzip (`.xplane.pb.gz`); its `planes` can be read more than once."""
     from jax.profiler import ProfileData
 
     if path.endswith(".gz"):
         with gzip.open(path) as f:
-            return reduce_planes(
-                ProfileData.from_serialized_xspace(f.read()).planes)
-    return reduce_planes(ProfileData.from_file(path).planes)
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
 
 
-def reduce_dir(log_dir: str) -> Summary:
-    """Reduce the one profile that `jax.profiler.trace(log_dir)` wrote."""
+def reduce_file(path: str) -> Summary:
+    """Reduce an `.xplane.pb` file, or its gzip (`.xplane.pb.gz`)."""
+    return reduce_planes(load_profile(path).planes)
+
+
+def find_profile(log_dir: str) -> str:
+    """The one profile that `jax.profiler.trace(log_dir)` wrote."""
     found = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if len(found) != 1:
         raise ValueError(f"expected one .xplane.pb under {log_dir}, "
                          f"found {len(found)}")
-    return reduce_file(found[0])
+    return found[0]
+
